@@ -110,6 +110,9 @@ def bench_schedule(reps, iters, quick):
     """) % (N_WORKERS, SRC, ROOT, N_WORKERS, reps, iters, DROP)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a host-device study: the child must never reach for an accelerator
+    # (on a chip machine the parent, which has imported jax, holds it)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=1200 if quick else 2400)
     if r.returncode != 0:

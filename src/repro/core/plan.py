@@ -79,7 +79,7 @@ class ExchangePlan:
     # the round's lowering (DESIGN.md §12): "xla" = psum_scatter +
     # all_gather per bucket (the seed schedule, bit-identical default);
     # "ring" = the fused ring engine (one Pallas dispatch per bucket on
-    # TPU, interpret ppermute ring elsewhere); "auto" = ring on TPU.
+    # TPU, interpret ppermute ring elsewhere); "auto" = xla.
     wire: str = "f32"
     # RS-leg codec (DESIGN.md §13): "f32" passthrough (bit-identical
     # default), "bf16" linear downcast, "int8" stochastic-rounding
@@ -383,7 +383,7 @@ def make_plan(tree: Any, n: int, s: Optional[int] = None, *,
 
     ``engine`` picks the round's lowering (DESIGN.md §12): "xla" (the
     seed two-collectives-per-bucket schedule, bit-identical default),
-    "ring" (the fused ring engine) or "auto" (ring on TPU).
+    "ring" (the fused ring engine) or "auto" (xla, DESIGN.md §12).
 
     ``wire``/``recovery`` pick the wire pipeline (DESIGN.md §13): the
     RS-leg codec ("f32" bit-identical default / "bf16" / "int8") and the

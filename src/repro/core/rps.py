@@ -44,7 +44,9 @@ Since DESIGN.md §12 the *lowering* of that round is pluggable
 TPU (n−1 ``make_async_remote_copy`` hops per phase, double-buffered, with
 in-kernel mask gating / renormalisation / AG-select and a donated table),
 and the bit-exact ``lax.ppermute`` interpret ring everywhere else.
-"auto" picks ring on TPU, xla elsewhere.
+"auto" is xla on every backend: the fused kernel holds a whole bucket in
+VMEM, which a real-width leaf does not fit, so "ring" stays an explicit
+choice until the kernel streams its table from HBM.
 
 Since DESIGN.md §13 the *wire treatment* is pluggable too: a
 :mod:`repro.core.wire` codec (``wire=`` — f32 passthrough / bf16 / int8
@@ -79,31 +81,25 @@ from repro.core import wire as wire_lib
 
 AxisNames = Union[str, Tuple[str, ...]]
 
+_LANE = 128     # TPU lane width: the row length of a block's payload
+
 
 def _axis_tuple(axis_name: AxisNames) -> Tuple[str, ...]:
     return (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
 
 
-def _one_axis_size(a: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(a)
-    from jax import core as _core       # jax < 0.5: static axis-env lookup
-    return int(_core.axis_frame(a))
-
-
 def axis_size(axis_name: AxisNames) -> int:
-    names = _axis_tuple(axis_name)
     n = 1
-    for a in names:
-        n *= _one_axis_size(a)
+    for a in _axis_tuple(axis_name):
+        n *= lax.axis_size(a)
     return n
 
 
 def _my_index(axis_name: AxisNames) -> jax.Array:
     names = _axis_tuple(axis_name)
     idx = lax.axis_index(names[0])
-    for a in names[1:]:       # _one_axis_size: jax<0.5 axis_size compat
-        idx = idx * _one_axis_size(a) + lax.axis_index(a)
+    for a in names[1:]:
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -213,10 +209,11 @@ ENGINES = ("auto", "xla", "ring")
 
 
 def resolve_engine(engine: Optional[str]) -> str:
-    """"auto" (and None) → the fused ring engine on TPU, the XLA
-    collective pair elsewhere. Static — resolved at trace time."""
+    """"auto" (and None) → the XLA collective pair on every backend (the
+    fused ring kernel keeps a whole bucket in VMEM; see the module
+    docstring). Static — resolved at trace time."""
     if engine is None or engine == "auto":
-        return "ring" if jax.default_backend() == "tpu" else "xla"
+        return "xla"
     if engine not in ("xla", "ring"):
         raise ValueError(f"engine={engine!r}, want one of {ENGINES}")
     return engine
@@ -479,6 +476,22 @@ def _exchange_table(blocks: jax.Array, rs: jax.Array, ag: jax.Array, *,
         return pin(out[:s])
 
 
+def _rows(d: int) -> Tuple[int, ...]:
+    """The shape a block's d payload elements are exchanged in: (d/128,
+    128) rows of the TPU's lane width where that divides, else flat. The
+    round's arithmetic is elementwise per block, so the view changes no
+    value; it keeps XLA's TPU compiler from spending time and host memory
+    in proportion to d on a flat operand (tens of GB at real widths)."""
+    return (d // _LANE, _LANE) if d % _LANE == 0 else (d,)
+
+
+def _lane_rows(tbl: jax.Array) -> jax.Array:
+    """A flat ``(s, blk, 1)`` bucket table in :func:`_rows` layout (a TP
+    bucket keeps its model dim last)."""
+    s, blk, m = tbl.shape
+    return tbl.reshape((s,) + _rows(blk)) if m == 1 else tbl
+
+
 def _bucket_masks(rs: jax.Array, ag: jax.Array, b: int):
     """Bucket b's (n, s) mask pair: per-bucket ``(n_buckets, n, s)`` masks
     index their own draw, legacy ``(n, s)`` masks are shared by every
@@ -712,9 +725,9 @@ def rps_exchange_plan(tree: Any, key: jax.Array, p: float,
     order = plan.ship_order
     outs: list = [None] * plan.n_buckets
     new_ef: list = [None] * plan.n_buckets
-    tbl = plan.gather_bucket(leaves, order[0])
+    tbl = _lane_rows(plan.gather_bucket(leaves, order[0]))
     for pos, b in enumerate(order):
-        nxt = plan.gather_bucket(leaves, order[pos + 1]) \
+        nxt = _lane_rows(plan.gather_bucket(leaves, order[pos + 1])) \
             if pos + 1 < plan.n_buckets else None  # prefetch next bucket
         rs_b, ag_b = _bucket_masks(rs, ag, b)
         late_b = (late["rs"][b], late["ag"][b]) if late is not None \
@@ -744,7 +757,7 @@ def rps_exchange_plan(tree: Any, key: jax.Array, p: float,
             # deterministic encode under EF: the feedback loop supplies
             # the unbiasing, so stochastic rounding's dither would only
             # add fresh variance the residual can never cancel
-            e_tbl = plan.gather_bucket(ef_leaves, b)
+            e_tbl = _lane_rows(plan.gather_bucket(ef_leaves, b))
             intent = tbl + e_tbl
             if codec.quantized:
                 send = codec.encode(intent, None)
@@ -1041,7 +1054,9 @@ def rps_exchange_global(tree: Any, key: jax.Array, p: float, n: int, *,
             enumerate(_global_groups(plan).items()):
         G = len(idxs)
         d = blk * m
-        stack = jnp.stack([tables[j].reshape(n, s, d) for j in idxs])
+        tail = _rows(d)
+        ex = (Ellipsis,) + (None,) * len(tail)    # (G, n, s) → payload
+        stack = jnp.stack([tables[j].reshape((n, s) + tail) for j in idxs])
         k_g = jax.random.fold_in(jax.random.fold_in(key, 0x77697265),
                                  g_idx) if codec.quantized else None
         if rs.ndim == 3:
@@ -1060,7 +1075,7 @@ def rps_exchange_global(tree: Any, key: jax.Array, p: float, n: int, *,
                 cm_g = jnp.broadcast_to(cmasks, (G, n, s))
             k_c = jax.random.fold_in(
                 jax.random.fold_in(key, _CORRUPT_TAG), g_idx)
-            stack_wire = corruption.apply(stack, cm_g[..., None], k_c)
+            stack_wire = corruption.apply(stack, cm_g[ex], k_c)
         else:
             stack_wire = stack
         if use_ef:
@@ -1072,12 +1087,12 @@ def rps_exchange_global(tree: Any, key: jax.Array, p: float, n: int, *,
             # deterministic encode under EF (see rps_exchange_plan): the
             # feedback loop unbiases, dither would only add variance
             ef_stack = jnp.stack(
-                [ef_tables[j].reshape(n, s, d) for j in idxs]
+                [ef_tables[j].reshape((n, s) + tail) for j in idxs]
             ).astype(stack.dtype)
             intent = stack + ef_stack
             send = to_wire(intent, None) if codec.quantized \
                 else codec.fake_quant(intent)
-            resid = jnp.where(rs_g[..., None] != 0,
+            resid = jnp.where(rs_g[ex] != 0,
                               intent - send.astype(stack.dtype), ef_stack)
             for pos, j in enumerate(idxs):
                 ef_outs[j] = resid[pos].astype(stack.dtype) \
@@ -1095,41 +1110,43 @@ def rps_exchange_global(tree: Any, key: jax.Array, p: float, n: int, *,
             # (G, n, s, d) → worker axis at -2 per (group, block) site,
             # masked by the delivery pattern — exactly the table the
             # collective xla path gathers
-            table = send.astype(jnp.float32).transpose(0, 2, 1, 3)
+            table = send.astype(jnp.float32).reshape(G, n, s, d) \
+                .transpose(0, 2, 1, 3)
             tilde = robust_lib.robust_aggregate(
                 table, rs_g.transpose(0, 2, 1) != 0, rec)    # (G, s, d)
+            tilde = tilde.reshape((G, s) + tail)
         elif engine == "ring":                # wire-dtype ring-order sums
             # the replay accumulates in the codec's accumulation dtype
             # (the wire itself for linear codecs — resolving wire= and
             # the legacy rs_dtype knob identically; f32 for quantised)
-            sums = ring_global_sums(send, rs_g, own,
+            sums = ring_global_sums(send.reshape(G, n, s, d), rs_g, own,
                                     rs_dtype=codec.accum_dtype,
-                                    codec=codec)
-            tilde = sums / div_g[..., None].astype(sums.dtype)
+                                    codec=codec).reshape((G, s) + tail)
+            tilde = sums / div_g[ex].astype(sums.dtype)
         elif use_pallas:
             # the kernel casts per-VMEM-tile internally: no (G,n,s,d)
             # f32 copy of the stack is ever materialised
-            blocks_k = send.transpose(0, 2, 1, 3).reshape(G * s, n, d)
+            blocks_k = jnp.moveaxis(send, 2, 1).reshape((G * s, n) + tail)
             mask_k = rs_g.transpose(0, 2, 1).reshape(G * s, n)
             tilde = masked_avg_grid_pallas(
-                blocks_k, mask_k, interpret=interp).reshape(G, s, d)
+                blocks_k, mask_k, interpret=interp).reshape((G, s) + tail)
         else:
             # the contraction runs on the *native*-dtype stack with f32
             # accumulation (preferred_element_type): a 0/1 mask is exact
             # in any float dtype and bf16→f32 products are exact, so the
             # sums are bit-identical to the old promote-then-einsum — but
             # no full-stack f32 copy is ever materialised
-            sums = jnp.einsum("gij,gijd->gjd", rs_g.astype(send.dtype),
+            sums = jnp.einsum("gij,gij...->gj...", rs_g.astype(send.dtype),
                               send, preferred_element_type=jnp.float32)
-            tilde = sums / div_g[..., None]
+            tilde = sums / div_g[ex]
         gathered = tilde.astype(stack.dtype)[:, None]  # AG moves payload
         if renorm:
             # the AG fallback *is* the input stack — no f32 copy of it
-            out = jnp.where(ag_g[..., None], gathered, stack)
+            out = jnp.where(ag_g[ex], gathered, stack)
         else:
             # grad mode: a dropped block means no update — multiply by
             # the mask instead of materialising a zeros fallback
-            out = gathered * ag_g[..., None].astype(stack.dtype)
+            out = gathered * ag_g[ex].astype(stack.dtype)
         for pos, j in enumerate(idxs):
             outs[j] = out[pos].reshape(n, s, blk, m)
     if use_ef:

@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this as TPUCompilerParams; accept both spellings
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) or \
-    pltpu.TPUCompilerParams
-
 
 def _rglru_kernel(x_ref, a_ref, o_ref, h_ref):
     c = pl.program_id(2)
@@ -72,7 +68,7 @@ def rglru_pallas(x, a, *, chunk: int = 128, tile_d: int = 256,
         out_shape=jax.ShapeDtypeStruct((B, Sp, dp), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, tile_d), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(x, a)
     return out[:, :S, :d]

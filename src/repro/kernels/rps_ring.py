@@ -212,8 +212,13 @@ def _make_ring_kernel(*, n: int, k: int, W: int, mode: str, rs_dtype,
                                    device_id_type=pltpu.DeviceIdType.LOGICAL)
         pltpu.semaphore_wait(barrier, 2)
 
+        def chunk_rows(c):
+            # k is padded to the packed sublane tile of every sub-32-bit
+            # operand (ring_bucket_fused), so Mosaic can prove alignment
+            return pl.ds(pl.multiple_of(c * k, k), k)
+
         def contrib(c):
-            rows = pl.ds(c * k, k)
+            rows = chunk_rows(c)
             if has_enc:     # decode fused into the gated accumulate
                 blk = qt_ref[rows, :].astype(rs_dtype) \
                     * qs_ref[rows, :].astype(rs_dtype)
@@ -282,13 +287,13 @@ def _make_ring_kernel(*, n: int, k: int, W: int, mode: str, rs_dtype,
             pltpu.semaphore_wait(cap_sem.at[t % 2], 1)
 
         # ---- turnaround: in-kernel recovery divisor --------------------
-        my_div = cnt_ref[pl.ds(i * k, k), :]                  # (k, 1)
+        my_div = cnt_ref[chunk_rows(i), :]                    # (k, 1)
         tilde = acc[...] / my_div
         mine = tilde.astype(payload_dtype)                    # (k, W)
 
         # ---- AG phase: select-as-it-lands ------------------------------
         def place(c, val):
-            rows = pl.ds(c * k, k)
+            rows = chunk_rows(c)
             keep = ag_ref[rows, :] != 0                       # (k, 1)
             if renorm:
                 fb = table_ref[rows, :]                       # local block
@@ -325,6 +330,16 @@ def _make_ring_kernel(*, n: int, k: int, W: int, mode: str, rs_dtype,
     return kernel
 
 
+def _aligned_rows(k: int, *dtypes) -> int:
+    """Rows per ring chunk: k rounded up to the packed sublane tile of
+    the narrowest operand (16 rows for bf16, 32 for int8). Mosaic refuses
+    a dynamic row slice of a packed dtype that it cannot prove tile-
+    aligned; 32-bit operands slice at any row."""
+    tile = max(1 if jnp.dtype(d).itemsize >= 4
+               else 32 // jnp.dtype(d).itemsize for d in dtypes)
+    return -(-k // tile) * tile
+
+
 @functools.partial(jax.jit, static_argnames=("n", "k", "mode", "rs_dtype",
                                              "collective_id", "interpret",
                                              "levels"))
@@ -358,7 +373,9 @@ def ring_bucket_fused(table: jax.Array, rs_row: jax.Array, ag_row: jax.Array,
     dispatch in every variant.
 
     The table is donated into the output (``input_output_aliases``): the
-    dispatch runs in place, no second (S, W) buffer.
+    dispatch runs in place, no second (S, W) buffer. Where a sub-32-bit
+    operand needs it, each owner's k rows are zero-padded to the packed
+    sublane tile around the dispatch (:func:`_aligned_rows`).
     """
     import jax.experimental.pallas.tpu as pltpu
     from jax.experimental import pallas as pl
@@ -374,13 +391,36 @@ def ring_bucket_fused(table: jax.Array, rs_row: jax.Array, ag_row: jax.Array,
     if levels > 0 and not has_enc:
         raise ValueError("levels > 0 needs qtable/qscale")
     rs_dtype = jnp.dtype(rs_dtype)
+    kp = _aligned_rows(k, table.dtype, rs_dtype,
+                       *((qtable.dtype,) if has_enc else ()))
+    if kp != k:
+        def pad_rows(x, fill=0.0):
+            x = x.reshape(n, k, x.shape[-1])
+            x = jnp.pad(x, ((0, 0), (0, kp - k), (0, 0)),
+                        constant_values=fill)
+            return x.reshape(n * kp, x.shape[-1])
+
+        # padding rows: RS mask 0 (they add nothing), AG mask 0 (they
+        # keep their zero local copy), divisor 1 (no 0/0)
+        table, rs_row, ag_row = (pad_rows(table), pad_rows(rs_row),
+                                 pad_rows(ag_row))
+        counts = pad_rows(counts, 1.0)
+        if has_enc:
+            qtable, qscale = pad_rows(qtable), pad_rows(qscale, 1.0)
+        out = ring_bucket_fused(table, rs_row, ag_row, counts, pos, left,
+                                right, n=n, k=kp, mode=mode,
+                                rs_dtype=rs_dtype,
+                                collective_id=collective_id,
+                                interpret=interpret, qtable=qtable,
+                                qscale=qscale, levels=levels)
+        return out.reshape(n, kp, W)[:, :k].reshape(S, W)
     kernel = _make_ring_kernel(
         n=n, k=k, W=W, mode=mode, rs_dtype=rs_dtype,
         payload_dtype=table.dtype,
         wire_dtype=None if not has_enc else jnp.dtype(qtable.dtype),
         levels=levels, has_enc=has_enc)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    vmem = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
     in_specs = [smem, smem, smem, vmem, vmem, vmem, vmem]
     args = [pos, left, right, table, rs_row, ag_row, counts]
     if has_enc:
@@ -421,7 +461,7 @@ def ring_bucket_fused(table: jax.Array, rs_row: jax.Array, ag_row: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, W), table.dtype),
         scratch_shapes=comm,
         input_output_aliases={3: 0},                # donate the table
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
         interpret=interpret,
     )(*args)
@@ -498,12 +538,14 @@ def ring_exchange_scatter_table(blocks: jax.Array, rs_sc: jax.Array,
                                 comm_slot: int = 0) -> jax.Array:
     """Ring-engine exchange of one scatter-ordered (S, blk[, m]) table.
 
-    ``use_kernel=None`` picks the fused Pallas dispatch on TPU (fully-
-    manual regions only — a ``pin`` hook marks a partial-manual region
-    whose auto-sharded dim Pallas cannot see) and the interpret-mode
-    ppermute ring everywhere else. ``ring_ids`` supplies precomputed
-    (pos, left, right) logical ids for multi-axis meshes
-    (:func:`logical_ring_ids`); defaults to a ring over the whole mesh.
+    ``use_kernel=None`` picks the fused Pallas dispatch on TPU and the
+    interpret-mode ppermute ring everywhere else. The kernel serves
+    fully-manual regions only: a ``pin`` hook marks a partial-manual
+    region whose auto-sharded dim Pallas cannot see, and on TPU that
+    raises rather than quietly replaying the ppermute schedule.
+    ``ring_ids`` supplies precomputed (pos, left, right) logical ids for
+    multi-axis meshes (:func:`logical_ring_ids`); defaults to a ring over
+    the whole mesh.
 
     Wire pipeline (DESIGN.md §13): a quantised ``codec`` routes through
     the int8-wire kernel variant — ``enc`` is the precomputed
@@ -524,7 +566,12 @@ def ring_exchange_scatter_table(blocks: jax.Array, rs_sc: jax.Array,
     round ``b`` overlap the compute that makes bucket ``b+1`` ready.
     """
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu" and pin is None
+        use_kernel = jax.default_backend() == "tpu"
+        if use_kernel and pin is not None:
+            raise ValueError(
+                "engine='ring' on TPU needs a fully-manual region: the "
+                "fused kernel cannot serve a partial-manual (pinned) "
+                "exchange — use engine='xla'")
     quantized = codec is not None and codec.quantized
     if not use_kernel:
         dec = codec.decode(*enc) if quantized and send is None else send

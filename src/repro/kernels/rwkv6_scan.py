@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this as TPUCompilerParams; accept both spellings
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) or \
-    pltpu.TPUCompilerParams
-
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref):
     c = pl.program_id(1)
@@ -100,7 +96,7 @@ def rwkv6_pallas(r, k, v, w, u, *, chunk: int = 64,
         out_shape=jax.ShapeDtypeStruct((B * h, Sp, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(rr, kk, vv, ww, uu)
     out = out.reshape(B, h, Sp, dv)[:, :, :S]

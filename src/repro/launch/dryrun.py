@@ -29,8 +29,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.launch import sharding as shlib
-from repro.launch.mesh import (make_production_mesh, mesh_context,
-                               rps_axes_for)
+from repro.launch.mesh import make_production_mesh, rps_axes_for
 from repro.models import build_model
 from repro.models.inputs import input_specs, train_specs
 from repro.models.registry import kind_sequence
@@ -171,7 +170,7 @@ def build_train_lowered(cfg: ArchConfig, shape: ShapeConfig, mesh,
                    in_shardings=in_sh,
                    out_shardings=out_sh,
                    donate_argnums=train_step.donate_argnums)
-    with mesh_context(mesh):      # with_sharding_constraint needs a context
+    with jax.set_mesh(mesh):      # with_sharding_constraint needs a context
         lowered = step.lower(params_shape, opt_shape, batch,
                              jnp.int32(0), jax.random.PRNGKey(0),
                              *((None, ef_shape) if efp else ()))
@@ -251,7 +250,7 @@ def build_decode_lowered(cfg: ArchConfig, shape: ShapeConfig, mesh,
                    in_shardings=(param_sh, cache_sh, tok_sh, None),
                    out_shardings=(None, cache_sh),
                    donate_argnums=(1,))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = step.lower(params_shape, cache_shape, tok, jnp.int32(S - 1))
     return lowered, {"cache_seq": S}
 
@@ -275,7 +274,7 @@ def build_prefill_lowered(cfg: ArchConfig, shape: ShapeConfig, mesh,
         for k, s in specs.items()}
 
     step = jax.jit(model.prefill, in_shardings=(param_sh, in_sh))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = step.lower(params_shape, specs)
     return lowered, {}
 
@@ -419,8 +418,7 @@ def main():
                     choices=["auto", "xla", "ring"],
                     help="RS+AG lowering (DESIGN.md §12): xla = 2 "
                          "collectives/bucket; ring = fused ring engine "
-                         "(1 Pallas dispatch/bucket on TPU); auto = ring "
-                         "on TPU")
+                         "(1 Pallas dispatch/bucket on TPU); auto = xla")
     ap.add_argument("--wire", default="f32",
                     choices=["f32", "bf16", "int8"],
                     help="RS-leg wire codec (DESIGN.md §13); int8 = 4x "

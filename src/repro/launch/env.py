@@ -12,7 +12,9 @@ pieces of host hygiene every launch used to hand-set (or forget):
     large-alloc report threshold high enough to keep it quiet.
 
 This module must stay importable *before* jax — XLA_FLAGS are read once
-at backend init — so it imports nothing heavy. Two entry points:
+at backend init — so it imports nothing heavy. It also places JAX's
+persistent compilation cache (:func:`use_compile_cache`). Two entry
+points for the host vars:
 
   * :func:`apply` — in-process: merge the computed vars into
     ``os.environ`` (call before the first jax import; ``LD_PRELOAD``
@@ -42,6 +44,27 @@ _TCMALLOC_GLOBS = (
     "/usr/lib/libtcmalloc*.so*",
     "/usr/local/lib/libtcmalloc*.so*",
 )
+
+
+#: the checkout root (this file is src/repro/launch/env.py)
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` — a fixed path, since the path is part of
+    the cache key and a directory that moves never hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    import jax
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def find_tcmalloc() -> Optional[str]:

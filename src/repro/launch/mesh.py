@@ -19,20 +19,7 @@ import jax
 
 
 def _axis_types(n: int) -> dict:
-    # explicit-sharding API gate (same condition the trainer tests skip
-    # on): older jax has no jax.sharding.AxisType and make_mesh rejects
-    # the kwarg — Auto is its implied default there, so omitting it is
-    # behaviour-identical and keeps the dryrun path importable
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n}
-
-
-def mesh_context(mesh):
-    """Context manager installing ``mesh`` for sharding constraints:
-    ``jax.set_mesh`` on the explicit-sharding API, the mesh's own
-    context manager (the legacy equivalent) on older jax."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.netsim import request_trace
 from repro.serve import (ContinuousEngine, ServeEngine, TPDecodeConfig,
@@ -66,6 +67,7 @@ def main():
     ap.add_argument("--telemetry-dir", default=None,
                     help="write a Chrome trace of the serving session here")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -97,7 +99,7 @@ def main():
         dt = time.time() - t0
         tps = args.batch * args.new_tokens / dt
         print(f"arch={cfg.name} generated {out.shape} in {dt:.2f}s "
-              f"({tps:.1f} tok/s on CPU)")
+              f"({tps:.1f} tok/s on {jax.devices()[0].platform})")
         print(np.asarray(out)[:2])
         return
 

@@ -1,10 +1,8 @@
-"""Training launcher.
-
-Two entry modes:
-  --sim      n-worker simulation on one device (paper-scale experiments;
-             global-view exchange, bit-identical to the collective path)
-  --devices  shard_map collective path over real/forced host devices
-             (e.g. XLA_FLAGS=--xla_force_host_platform_device_count=8)
+"""Training launcher: the n-worker simulation on one device
+(``repro.train.simulator``: paper-scale experiments, global-view exchange,
+bit-identical to the collective path). The mesh trainer
+(``repro.train.trainer``) has no launcher; ``chip_smoke.py --chips 4``
+drives it on four chips.
 
 Example (the end-to-end ~100M driver is examples/train_rps_100m.py):
   PYTHONPATH=src python -m repro.launch.train --arch rps-paper-mlp \
@@ -23,8 +21,14 @@ import numpy as np
 from repro.checkpoint import save_pytree
 from repro.configs import get_config
 from repro.data.synthetic import CharLMTask, make_worker_streams
+from repro.launch.env import use_compile_cache
 from repro.models import build_model
 from repro.train.simulator import SimulatorConfig, run_simulation
+
+#: largest token alphabet of the synthetic Markov task: its (V, V)
+#: transition table is built on the host, 84 GB at a 102400-token vocab.
+#: Larger models see token ids below this bound; their widths are untouched.
+TASK_VOCAB = 4096
 
 
 def _float_or_auto(v: str):
@@ -149,13 +153,14 @@ def main():
                          "trace.json here (implies --telemetry); render "
                          "with tools/render_experiments.py --telemetry DIR")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg, grouped=False)
-    task = CharLMTask(vocab=cfg.vocab_size, seq_len=args.seq_len,
-                      seed=args.seed)
+    task = CharLMTask(vocab=min(cfg.vocab_size, TASK_VOCAB),
+                      seq_len=args.seq_len, seed=args.seed)
     batch_fn = make_worker_streams(task, args.workers, args.batch_size)
 
     def loss_fn(p, b):

@@ -461,6 +461,9 @@ def run_simulation(loss_fn: Callable, init_fn: Callable,
     p1 = init_fn(k_init)
     params = jax.tree.map(
         lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy(), p1)
+    # the plan reads shapes only: keep no device copy of the unstacked
+    # replica alive through the run (a whole model's worth of memory)
+    p1 = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), p1)
     opt = make_optimizer(scfg.optimizer,
                          state_pack=getattr(scfg, "state_pack", None))
     opt_state = opt.init(params)

@@ -99,8 +99,8 @@ class TrainConfig:
                                            # ring engine (one Pallas
                                            # dispatch per bucket on TPU,
                                            # interpret ppermute ring
-                                           # elsewhere); "auto" = ring on
-                                           # TPU, xla elsewhere.
+                                           # elsewhere); "auto" = xla on
+                                           # every backend (DESIGN §12).
     wire: str = "f32"                      # RS-leg codec (DESIGN.md §13):
                                            # "f32" bit-identical default,
                                            # "bf16" (absorbs a bf16
@@ -172,12 +172,23 @@ def _is_model_mode(agg: str) -> bool:
 
 
 def _shard_map(f, mesh, in_specs, out_specs, axis_names):
-    if hasattr(jax, "shard_map"):                 # jax >= 0.6
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names)
-    from jax.experimental.shard_map import shard_map as sm   # jax < 0.6
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names)
+
+
+def _on_mesh(specs: Any, mesh: Mesh) -> Any:
+    """Param PartitionSpecs with the axes ``mesh`` lacks dropped: on a
+    ("data",)-only mesh the tensor-parallel ("model") dims are replicated,
+    each worker holding its whole replica."""
+    def keep(ent):
+        if ent is None:
+            return None
+        axes = tuple(a for a in (ent if isinstance(ent, tuple) else (ent,))
+                     if a in mesh.axis_names)
+        return axes if len(axes) > 1 else (axes[0] if axes else None)
+
+    return jax.tree.map(lambda sp: P(*(keep(e) for e in sp)), specs,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def _local_struct(params_shape: Any, especs: Any, mesh: Mesh) -> Any:
@@ -275,8 +286,9 @@ def make_train_setup(model: Model, cfg: ArchConfig, tcfg: TrainConfig,
     # (hoisted out of the traced step — the seed recomputed eval_shape +
     # param_specs twice per trace: once in train_step, again in _exchange)
     params_shape = jax.eval_shape(init_state, jax.random.PRNGKey(0))[0]
-    especs = shlib.param_specs(params_shape, cfg, worker_axes=rps_axes,
-                               fsdp_axis=fsdp_axis, stacked=True)
+    especs = _on_mesh(shlib.param_specs(
+        params_shape, cfg, worker_axes=rps_axes, fsdp_axis=fsdp_axis,
+        stacked=True), mesh)
     plan = None
     if rps_agg:
         local_shape = _local_struct(params_shape, especs, mesh)
@@ -303,8 +315,9 @@ def make_train_setup(model: Model, cfg: ArchConfig, tcfg: TrainConfig,
 
     # ---- shardings --------------------------------------------------------
     def state_shardings(params_shape):
-        pspecs = shlib.param_specs(params_shape, cfg, worker_axes=rps_axes,
-                                   fsdp_axis=fsdp_axis, stacked=True)
+        pspecs = _on_mesh(shlib.param_specs(
+            params_shape, cfg, worker_axes=rps_axes, fsdp_axis=fsdp_axis,
+            stacked=True), mesh)
         return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs), pspecs
 
     def _exchange(tree, key, mode=None, masks=None, ef=None, cmask=None):
@@ -332,8 +345,13 @@ def make_train_setup(model: Model, cfg: ArchConfig, tcfg: TrainConfig,
         if tcfg.aggregator == "none" or n_rps == 1:
             return tree if ef is None else (tree, ef)
         if tcfg.aggregator.startswith("allreduce"):
-            out = jax.tree.map(lambda x: jnp.broadcast_to(
-                jnp.mean(x, axis=0, keepdims=True), x.shape), tree)
+            # pinned to the param shardings: left to the compiler, the
+            # broadcast mean comes out replicated — every device holding
+            # all n replicas (n x the model per chip)
+            out = jax.tree.map(lambda x, sp: jax.lax.with_sharding_constraint(
+                jnp.broadcast_to(jnp.mean(x, axis=0, keepdims=True),
+                                 x.shape), NamedSharding(mesh, sp)),
+                tree, especs)
             return out if ef is None else (out, ef)
         if mode is None:
             mode = ("model" if _is_model_mode(tcfg.aggregator)

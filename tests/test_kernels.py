@@ -34,7 +34,7 @@ def test_masked_avg_all_dropped_but_owner():
                                rtol=1e-6)
 
 
-@pytest.mark.parametrize("B", [1, 3, 16])
+@pytest.mark.parametrize("B", [1, 3, 16, 13])
 @pytest.mark.parametrize("n,d", [(2, 7), (8, 512), (16, 1000)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_masked_avg_grid_sweep(B, n, d, dtype):
@@ -43,7 +43,7 @@ def test_masked_avg_grid_sweep(B, n, d, dtype):
     blocks = jnp.asarray(RNG.normal(size=(B, n, d)), dtype)
     mask = jnp.asarray(RNG.integers(0, 2, size=(B, n)),
                        jnp.float32).at[:, 0].set(1)
-    got = masked_avg_grid_pallas(blocks, mask, tile_d=256, interpret=True)
+    got = masked_avg_grid_pallas(blocks, mask, tile_rows=2, interpret=True)
     f32 = blocks.astype(jnp.float32)
     want = jnp.einsum("bn,bnd->bd", mask, f32) \
         / jnp.maximum(mask.sum(-1), 1.0)[:, None]
@@ -59,9 +59,9 @@ def test_masked_avg_grid_matches_per_block_vmap():
     blocks = jnp.asarray(RNG.normal(size=(B, n, d)), jnp.float32)
     mask = jnp.asarray(RNG.integers(0, 2, size=(B, n)),
                        jnp.float32).at[:, 0].set(1)
-    got = masked_avg_grid_pallas(blocks, mask, tile_d=128, interpret=True)
+    got = masked_avg_grid_pallas(blocks, mask, tile_rows=1, interpret=True)
     want = jax.vmap(lambda b, m: masked_avg_pallas(
-        b, m, tile_d=128, interpret=True))(blocks, mask)
+        b, m, tile_rows=1, interpret=True))(blocks, mask)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -72,20 +72,25 @@ def test_masked_avg_grid_rejects_bad_mask_shape():
 
 
 def test_masked_avg_tile_d_auto_divisor():
-    """tile_d=None picks d itself below the cap (no padded lanes — the
-    seed default of 512 padded a d=40 sweep to 512) and a divisor of d in
-    [128, 512] above it (no ragged last tile)."""
-    from repro.kernels.masked_avg import pick_tile_d
-    assert pick_tile_d(40) == 40          # tiny model: one exact tile
-    assert pick_tile_d(512) == 512
-    assert pick_tile_d(1) == 1
-    assert pick_tile_d(1000) == 500       # divisor, not 512-with-pad
-    assert pick_tile_d(1024) == 512
-    assert pick_tile_d(513) == 171        # 513 = 3·171
-    assert pick_tile_d(1021) == 512       # prime: cap + end padding
-    for d in (40, 1000, 513):
-        t = pick_tile_d(d)
-        assert d % t == 0 and t <= 512
+    """tile_rows=None takes every row of a block in one step while the
+    (n, rows, 128) stack fits the 2 MiB budget, above it the largest
+    multiple of 32 rows under the budget that divides the row count (no
+    ragged last tile), falling back to the budget tile with padding:
+    Mosaic refuses a tile that is neither the whole dim nor a multiple of
+    the sublane tile."""
+    from repro.kernels.masked_avg import pick_tile_rows
+    assert pick_tile_rows(1, 8, 4) == 1        # tiny model: one exact tile
+    assert pick_tile_rows(100, 2, 2) == 100
+    assert pick_tile_rows(4096, 2, 2) == 4096  # exactly the budget
+    # deepseek-7b embedding, 2 server blocks: 102400·4096/2/128 rows
+    assert pick_tile_rows(1638400, 2, 2) == 4096
+    assert pick_tile_rows(12320, 2, 2) == 2464  # 12320 = 32·5·7·11
+    assert pick_tile_rows(8193, 2, 2) == 4096   # odd: budget + padding
+    assert pick_tile_rows(1000, 16, 4) == 256   # f32 n=16 budget; pad
+    for rows, n, size in ((1, 2, 4), (12320, 2, 2), (8193, 2, 2),
+                          (1638400, 2, 2), (5000, 64, 4)):
+        t = pick_tile_rows(rows, n, size)
+        assert t == rows or (t % 32 == 0 and n * t * 128 * size <= 2 << 20)
 
 
 @pytest.mark.parametrize("d", [40, 513, 1000])
@@ -99,7 +104,7 @@ def test_masked_avg_auto_tile_matches_explicit(d):
                          bool).at[:, 0].set(True)
     got = masked_avg_grid_pallas(blocks, mask_b, interpret=True)
     want = masked_avg_grid_pallas(blocks, mask_b.astype(jnp.float32),
-                                  tile_d=8, interpret=True)
+                                  tile_rows=1, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

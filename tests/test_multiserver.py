@@ -26,12 +26,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _shard_map(f, mesh, in_specs, out_specs, axis_names):
-    if hasattr(jax, "shard_map"):                 # jax >= 0.6
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=False)
 
 
 def _oracle(V, rs, ag, mode):

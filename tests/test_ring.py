@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro import channels as channels_lib
 from repro.core import plan as plan_lib
@@ -364,12 +365,15 @@ def test_ring_tpu_export_one_fused_dispatch_per_bucket():
                 mode="model", rs_dtype=wire, collective_id=cid))
         return outs
 
-    try:
-        from jax import export
-    except ImportError:
-        pytest.skip("jax.export unavailable")
-    args = [jnp.zeros((S, W), pdt) for (W, pdt, _) in buckets]
-    exp = export.export(jax.jit(round_fn), platforms=("tpu",))(*args)
+    from jax import export
+    # the kernel's RDMAs need a manual region: one (S, W) table per device
+    # of an n-device ring (an abstract mesh — nothing is placed)
+    mesh = jax.sharding.AbstractMesh((n,), ("x",))
+    specs = tuple(P("x") for _ in buckets)
+    ring = jax.shard_map(round_fn, mesh=mesh, in_specs=specs,
+                         out_specs=list(specs), check_vma=False)
+    args = [jax.ShapeDtypeStruct((n * S, W), pdt) for (W, pdt, _) in buckets]
+    exp = export.export(jax.jit(ring), platforms=("tpu",))(*args)
     txt = exp.mlir_module()
     counts = check_hlo.summarize(txt)
     assert counts["tpu_custom_call"] == len(buckets), counts
@@ -403,6 +407,27 @@ def test_exchange_table_forwards_raw_pin_to_ring(monkeypatch):
                         n=n, i=jnp.int32(0), mode="model", engine="ring",
                         pin=tp_pin)
     assert seen["pin"] is tp_pin
+
+
+def test_auto_engine_is_xla_on_every_backend(monkeypatch):
+    """The fused kernel keeps a whole bucket in VMEM, which a real-width
+    leaf does not fit: "auto" never picks it, on TPU either."""
+    assert rps.resolve_engine("auto") == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rps.resolve_engine("auto") == rps.resolve_engine(None) == "xla"
+    assert rps.resolve_engine("ring") == "ring"
+
+
+def test_ring_with_pin_raises_on_tpu(monkeypatch):
+    """A pinned (partial-manual) exchange cannot use the fused kernel: on
+    TPU that is an error, not a silent ppermute replay."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 4
+    rs_m, ag_m = rps.sample_masks(KEY, n, 0.2)
+    with pytest.raises(ValueError, match="fully-manual"):
+        rps_ring.ring_exchange_scatter_table(
+            jnp.zeros((n, 8)), rs_m, ag_m, names=("data",), n=n,
+            i=jnp.int32(0), k=1, mode="model", pin=lambda x: x)
 
 
 def test_ring_bucket_fused_validates_layout():

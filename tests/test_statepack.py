@@ -532,6 +532,28 @@ def test_env_cli_emits_eval_able_preamble():
     assert "--xla_force_host_platform_device_count=5" in r.stdout
 
 
+@pytest.mark.parametrize("from_env", [True, False])
+def test_env_use_compile_cache(monkeypatch, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; unset,
+    the cache sits at the fixed <checkout>/.jax_cache (the path is part
+    of the cache key: a moving directory never hits)."""
+    old = jax.config.jax_compilation_cache_dir
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+            assert env_lib.use_compile_cache() == "cache-from-env"
+            assert jax.config.jax_compilation_cache_dir == old
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = env_lib.use_compile_cache()
+            assert path == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert env_lib.use_compile_cache() == path     # fixed, stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
 # ---- --compute-ms=auto: measured bucket readiness -------------------------
 
 def test_with_ready_ms_validation():

@@ -287,17 +287,9 @@ def test_registry_writes_artifacts(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# trainer path (subprocess: needs the jax>=0.6 explicit-sharding API)
+# trainer path (subprocess: 8 forced host devices)
 # ---------------------------------------------------------------------------
 
-NEW_SHARDING_API = (hasattr(jax.sharding, "AxisType")
-                    and hasattr(jax, "set_mesh")
-                    and hasattr(jax, "shard_map"))
-
-
-@pytest.mark.skipif(
-    not NEW_SHARDING_API,
-    reason="needs the jax>=0.6 explicit-sharding API")
 def test_trainer_telemetry_bit_identical():
     code = textwrap.dedent("""
         import os

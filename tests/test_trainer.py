@@ -6,20 +6,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-# the trainer targets the explicit-sharding API (jax.make_mesh axis_types,
-# jax.set_mesh, top-level jax.shard_map); older jax (< 0.6) lacks it
-NEW_SHARDING_API = (hasattr(jax.sharding, "AxisType")
-                    and hasattr(jax, "set_mesh")
-                    and hasattr(jax, "shard_map"))
-pytestmark = pytest.mark.skipif(
-    not NEW_SHARDING_API,
-    reason="needs the jax>=0.6 explicit-sharding API "
-           "(jax.sharding.AxisType / jax.set_mesh / jax.shard_map)")
 
 
 def _run(code: str) -> str:
