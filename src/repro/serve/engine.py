@@ -24,9 +24,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import Model
-from repro.serve.kvcache import PagedCache, n_pages
+from repro.serve.kvcache import WRITE_PROGRAM, PagedCache, n_pages
 from repro.serve.scheduler import FINISHED, RUNNING, Request, Scheduler
 from repro.serve.tp import TPDecodeConfig, make_tp_context
+from repro.telemetry.trace import NULL_SPAN
 
 
 def make_serve_steps(model: Model, max_len: Optional[int] = None):
@@ -116,6 +117,42 @@ class ServeReport:
         return {r.rid: list(r.generated) for r in self.requests}
 
 
+#: XLA module names of ``ContinuousEngine``'s programs, as a profile names
+#: their runs: the prefill, and the fused decode round
+PREFILL_PROGRAM = "jit__lambda"
+ROUND_PROGRAM = "jit_round_fn"
+#: the programs a ``serve.prefill`` span runs (';'-joined: the profiler
+#: splits an annotation's arg at commas)
+PREFILL_PROGRAMS = f"{PREFILL_PROGRAM};{WRITE_PROGRAM}"
+
+
+def _no_span(name, **args):
+    return NULL_SPAN
+
+
+def _fetch(x: jax.Array) -> np.ndarray:
+    """``x`` on the host. The copy is queued behind the program that makes
+    ``x`` and the wait for that program runs under no JAX profiler span
+    (``np.asarray`` opens one, ``block_until_ready`` does not), so a
+    profile's idle gap after the program falls to the engine span that
+    holds the wait, not to ``np.asarray``."""
+    x.copy_to_host_async()
+    x.block_until_ready()
+    return np.asarray(x)
+
+
+def round_counts(pos: np.ndarray, n_left: np.ndarray, chunk: int
+                 ) -> Dict[str, int]:
+    """What one decode round does, from the lanes' host positions and
+    tokens left (0 on an idle lane): ``steps`` token steps with a live
+    lane, ``tokens`` emitted, and ``kv_reads`` K/V positions read — a
+    lane live at step s reads positions 0..pos+s."""
+    k = np.minimum(n_left, chunk).astype(np.int64)
+    p = pos.astype(np.int64)
+    return {"steps": int(k.max(initial=0)), "tokens": int(k.sum()),
+            "kv_reads": int((k * (p + 1) + k * (k - 1) // 2).sum())}
+
+
 @dataclasses.dataclass
 class ContinuousEngine:
     """Continuous-batching paged-KV serving engine.
@@ -128,6 +165,14 @@ class ContinuousEngine:
     the per-layer decode collectives onto the drop-masked exchange; left
     inactive, the engine is pinned bit-identical to :class:`ServeEngine`
     greedy decoding (tests/test_serve_continuous.py).
+
+    With ``telemetry`` set, each loop iteration records nested host spans
+    through ``telemetry.trace.span`` (``serve.step`` over ``serve.admit``,
+    ``serve.prefill``, ``serve.batch``, ``serve.round``, ``serve.advance``;
+    ``serve.wait`` while nothing is due), one ``serve.queue`` counter after
+    ``serve.step`` closes, and per-request ``serve.request`` events via
+    ``now_us``/``complete``. A span's context value takes args known only
+    at its end through ``set_metadata``, as TraceAnnotation's does.
     """
     model: Model
     params: Any
@@ -152,7 +197,7 @@ class ContinuousEngine:
         self.max_pages = self.max_len // self.page
         self.tp_ctx = make_tp_context(self.tp, self.model.cfg,
                                       self.max_batch)
-        self._prefill = jax.jit(
+        self._prefill = jax.jit(                   # PREFILL_PROGRAM
             lambda params, toks: self.model.prefill(params,
                                                     {"tokens": toks},
                                                     paged=True))
@@ -197,7 +242,7 @@ class ContinuousEngine:
                 step, carry, None, length=chunk)
             return pool, toks, ch_state       # toks: (chunk, B)
 
-        return jax.jit(round_fn, donate_argnums=(1,))
+        return jax.jit(round_fn, donate_argnums=(1,))   # ROUND_PROGRAM
 
     # -- session ------------------------------------------------------------
 
@@ -227,84 +272,98 @@ class ContinuousEngine:
                     if self.tp_ctx is not None else None)
         reg = self.telemetry
         tel = reg.trace if reg is not None else None
+        span = tel.span if tel is not None else _no_span
         t0 = time.perf_counter()
         now = lambda: (time.perf_counter() - t0) * 1e3     # noqa: E731
         rounds = prefills = 0
 
         while pending or not sched.idle:
-            t = now()
-            while pending and (drain or pending[0].arrival_ms <= t):
-                sched.add(pending.pop(0))
-            if sched.idle and pending:
-                time.sleep(
-                    min(max(pending[0].arrival_ms - now(), 0.0), 50.0)
-                    / 1e3)
+            if sched.idle and not drain and pending[0].arrival_ms > now():
+                with span("serve.wait"):
+                    time.sleep(
+                        min(max(pending[0].arrival_ms - now(), 0.0), 50.0)
+                        / 1e3)
                 continue
 
-            admitted, _ = sched.schedule()
-            # preempted/finished requests lose their lane
-            for i, r in enumerate(lanes):
-                if r is not None and r.state != RUNNING:
-                    lanes[i] = None
+            with span("serve.step"):
+                with span("serve.admit") as sp:
+                    t = now()
+                    while pending and (drain or pending[0].arrival_ms <= t):
+                        sched.add(pending.pop(0))
+                    admitted, _ = sched.schedule()
+                    # preempted/finished requests lose their lane
+                    for i, r in enumerate(lanes):
+                        if r is not None and r.state != RUNNING:
+                            lanes[i] = None
+                    sp.set_metadata(admitted=len(admitted))
 
-            for r in admitted:
-                full = np.concatenate(
-                    [r.prompt, np.asarray(r.generated, np.int32)])
-                if tel is not None:
-                    with tel.span("serve.prefill", rid=r.rid,
-                                  tokens=int(full.size)):
+                for r in admitted:
+                    full = np.concatenate(
+                        [r.prompt, np.asarray(r.generated, np.int32)])
+                    with span("serve.prefill", rid=r.rid,
+                              tokens=int(full.size),
+                              first=int(r.admitted_ms is None),
+                              wait_ms=now() - r.arrival_ms,
+                              programs=PREFILL_PROGRAMS):
                         last, pcache = self._prefill(
                             self.params, jnp.asarray(full[None, :]))
-                else:
-                    last, pcache = self._prefill(
-                        self.params, jnp.asarray(full[None, :]))
-                cache.write_prefill(pcache, r.blocks, int(full.size))
-                prefills += 1
-                if r.admitted_ms is None:
-                    r.admitted_ms = now()
-                if tel is not None and getattr(r, "_ts_us", None) is None:
-                    r._ts_us = tel.now_us()
-                tok0 = int(jnp.argmax(last[0]))
-                if r.first_token_ms is None:
-                    r.first_token_ms = now()
-                sched.advance(r, [tok0])
-                if r.state == RUNNING:
-                    lane = lanes.index(None)
-                    lanes[lane] = r
-                    r.lane = lane
-                elif r.state == FINISHED:
-                    self._finish(r, now(), tel)
+                        cache.write_prefill(pcache, r.blocks, int(full.size))
+                        if r.admitted_ms is None:
+                            r.admitted_ms = now()
+                        if tel is not None and \
+                                getattr(r, "_ts_us", None) is None:
+                            r._ts_us = tel.now_us()
+                        tok0 = int(_fetch(jnp.argmax(last[0])))
+                        if r.first_token_ms is None:
+                            r.first_token_ms = now()
+                    prefills += 1
+                    sched.advance(r, [tok0])
+                    if r.state == RUNNING:
+                        lane = lanes.index(None)
+                        lanes[lane] = r
+                        r.lane = lane
+                    elif r.state == FINISHED:
+                        self._finish(r, now(), tel)
 
-            live = [r for r in lanes if r is not None]
-            if live:
-                bt = np.zeros((self.max_batch, self.max_pages), np.int32)
-                pos = np.zeros(self.max_batch, np.int32)
-                n_left = np.zeros(self.max_batch, np.int32)
-                tok = np.zeros((self.max_batch, 1), np.int32)
-                for i, r in enumerate(lanes):
-                    if r is None:
-                        continue
-                    bt[i] = cache.block_row(r.blocks, self.max_pages)
-                    pos[i] = r.pos
-                    n_left[i] = r.n_left
-                    tok[i, 0] = r.generated[-1]
-                key, k_r = jax.random.split(key)
-                pool, toks, ch_state = self._round(
-                    self.params, cache.pool, jnp.asarray(bt),
-                    jnp.asarray(tok), jnp.asarray(pos),
-                    jnp.asarray(n_left), k_r, ch_state)
-                cache.pool = pool
-                toks_np = np.asarray(toks)
-                rounds += 1
-                t_end = now()
-                for i, r in enumerate(lanes):
-                    if r is None:
-                        continue
-                    k = min(self.chunk, r.n_left)
-                    sched.advance(r, toks_np[:k, i].tolist())
-                    if r.state == FINISHED:
-                        lanes[i] = None
-                        self._finish(r, t_end, tel)
+                live = [r for r in lanes if r is not None]
+                if live:
+                    with span("serve.batch", lanes=len(live)):
+                        bt = np.zeros((self.max_batch, self.max_pages),
+                                      np.int32)
+                        pos = np.zeros(self.max_batch, np.int32)
+                        n_left = np.zeros(self.max_batch, np.int32)
+                        tok = np.zeros((self.max_batch, 1), np.int32)
+                        for i, r in enumerate(lanes):
+                            if r is None:
+                                continue
+                            bt[i] = cache.block_row(r.blocks, self.max_pages)
+                            pos[i] = r.pos
+                            n_left[i] = r.n_left
+                            tok[i, 0] = r.generated[-1]
+                        args = (jnp.asarray(bt), jnp.asarray(tok),
+                                jnp.asarray(pos), jnp.asarray(n_left))
+                        key, k_r = jax.random.split(key)
+                    with (span("serve.round", program=ROUND_PROGRAM,
+                               **round_counts(pos, n_left, self.chunk))
+                          if tel is not None else NULL_SPAN):
+                        pool, toks, ch_state = self._round(
+                            self.params, cache.pool, *args, k_r, ch_state)
+                        cache.pool = pool
+                        toks_np = _fetch(toks)
+                        t_end = now()
+                    rounds += 1
+                    with span("serve.advance") as sp:
+                        finished = 0
+                        for i, r in enumerate(lanes):
+                            if r is None:
+                                continue
+                            k = min(self.chunk, r.n_left)
+                            sched.advance(r, toks_np[:k, i].tolist())
+                            if r.state == FINISHED:
+                                lanes[i] = None
+                                self._finish(r, t_end, tel)
+                                finished += 1
+                        sp.set_metadata(finished=finished)
             if tel is not None:
                 tel.counter("serve.queue", {
                     "waiting": len(sched.waiting),

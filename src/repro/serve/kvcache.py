@@ -80,6 +80,10 @@ class BlockAllocator:
         self._free.extend(reversed(ids))
 
 
+#: XLA module name of the prefill scatter, as a profile names its runs
+WRITE_PROGRAM = "jit_write"
+
+
 @dataclasses.dataclass
 class PagedCache:
     """The device pool + its host-side accounting for one serving session.
@@ -118,7 +122,7 @@ class PagedCache:
                             src[leaf][:, 0].astype(dst[leaf].dtype))
                     return out
                 return {kn: one(kn) for kn in pool}
-            fn = jax.jit(write, donate_argnums=(0,))
+            fn = jax.jit(write, donate_argnums=(0,))     # WRITE_PROGRAM
             self._writers[length] = fn
         return fn
 

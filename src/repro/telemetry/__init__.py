@@ -7,7 +7,7 @@ Layers, bottom up:
   ``counters``   mask-derived delivery counts, divisor stats, norms
   ``estimator``  per-link effective-p EWMA + theory-drift monitor
   ``trace``      Chrome-trace span buffer + schema validation
-  ``sinks``      JSONL / in-memory ring / terminal-table record sinks
+  ``sinks``      JSONL / in-memory ring record sinks
   ``record``     JSON-ready step records + the RunHistory container
   ``registry``   the per-run Telemetry object tying it all together
   ``timing``     the unified bench timer (time_fn / wallclock)

@@ -24,8 +24,7 @@ import numpy as np
 
 from repro.telemetry.estimator import LinkRateEstimator
 from repro.telemetry.record import make_step_record, to_jsonable
-from repro.telemetry.sinks import ConsoleSink, JsonlSink, MemorySink, \
-    close_all
+from repro.telemetry.sinks import JsonlSink, MemorySink, close_all
 from repro.telemetry.trace import TraceBuffer
 
 _current: Optional["Telemetry"] = None
@@ -55,12 +54,11 @@ class Telemetry:
 
     ``out_dir=None`` keeps everything in memory (MemorySink) until
     :meth:`finalize`; a directory attaches a streaming JSONL sink
-    immediately. ``console_every > 0`` adds a live terminal summary.
+    immediately.
     """
 
     def __init__(self, out_dir: Optional[str] = None,
-                 estimator_alpha: Optional[float] = None,
-                 console_every: int = 0):
+                 estimator_alpha: Optional[float] = None):
         self.out_dir = out_dir
         self.estimator_alpha = estimator_alpha
         self.trace = TraceBuffer()
@@ -70,8 +68,6 @@ class Telemetry:
             os.makedirs(out_dir, exist_ok=True)
             self.sinks.append(JsonlSink(os.path.join(out_dir,
                                                      "telemetry.jsonl")))
-        if console_every:
-            self.sinks.append(ConsoleSink(every=console_every))
         self.meta: Dict[str, Any] = {}
         self.timings: Dict[str, List[float]] = {}
         self.rs_est: Optional[LinkRateEstimator] = None

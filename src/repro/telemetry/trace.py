@@ -8,15 +8,46 @@ rides on ``jax.named_scope`` inside the jitted step (``core/rps.py``):
 those names land in XLA's own profiler timeline on TPU; this buffer is
 the host view that works everywhere, no profiler needed.
 
+Events are stamped on the wall clock (µs since the epoch), the clock of
+the JAX profiler's host events, and every span is also opened as a
+``jax.profiler.TraceAnnotation`` with the same args: a buffer's Chrome
+trace and an ``.xplane.pb`` of the same run overlay.
+
 ``python -m repro.telemetry.trace --validate FILE`` exits non-zero on a
 malformed trace — the CI schema gate.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+
+class SpanArgs(dict):
+    """The args of an open span. ``set_metadata(**more)`` adds args known
+    only as the span ends, in the Chrome event and in the profiler's
+    annotation alike (the name is TraceAnnotation's own method, so code
+    that records spans can hold either)."""
+
+    def __init__(self, args: Dict[str, Any], ann=None):
+        super().__init__(args)
+        self._ann = ann
+
+    def set_metadata(self, **more) -> None:
+        self.update(more)
+        if self._ann is not None:
+            self._ann.set_metadata(**more)
+
+
+class _NoArgs:
+    def set_metadata(self, **more) -> None:
+        pass
+
+
+#: the span of a run that records none: one shared no-op context manager
+NULL_SPAN = contextlib.nullcontext(_NoArgs())
 
 
 class TraceBuffer:
@@ -25,15 +56,15 @@ class TraceBuffer:
     def __init__(self, pid: int = 0):
         self.pid = pid
         self.events: List[Dict[str, Any]] = []
-        self._t0 = time.perf_counter()
 
     def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return time.time_ns() / 1e3
 
     def now_us(self) -> float:
-        """Buffer-clock timestamp for callers that record a span's start
-        and emit it later via :meth:`complete` (e.g. per-request serving
-        spans that straddle many decode rounds)."""
+        """Buffer-clock timestamp (wall clock, µs since the epoch) for
+        callers that record a span's start and emit it later via
+        :meth:`complete` (e.g. per-request serving spans that straddle
+        many decode rounds)."""
         return self._now_us()
 
     def complete(self, name: str, ts_us: float, dur_us: float,
@@ -51,20 +82,22 @@ class TraceBuffer:
 
     @contextmanager
     def span(self, name: str, tid: int = 0, **args):
-        """Time a host-side phase; also forwards the name to the JAX
-        profiler (TraceAnnotation) so device timelines line up when a
-        profiler session is active."""
+        """Time a host-side phase; also opens a JAX profiler
+        TraceAnnotation with the same name and args, so device timelines
+        line up when a profiler session is active. Yields the span's
+        :class:`SpanArgs`."""
         t0 = self._now_us()
-        ann = _profiler_annotation(name)
+        ann = _profiler_annotation(name, args)
+        held = SpanArgs(args, ann)
         try:
-            yield
+            yield held
         finally:
             if ann is not None:
                 ann.__exit__(None, None, None)
             ev = {"name": name, "ph": "X", "ts": t0,
                   "dur": self._now_us() - t0, "pid": self.pid, "tid": tid}
-            if args:
-                ev["args"] = {k: v for k, v in args.items()}
+            if held:
+                ev["args"] = dict(held)
             self.events.append(ev)
 
     def instant(self, name: str, tid: int = 0, **args) -> None:
@@ -89,12 +122,13 @@ class TraceBuffer:
             json.dump(self.to_chrome(), f)
 
 
-def _profiler_annotation(name: str):
+def _profiler_annotation(name: str, args: Dict[str, Any]):
     """Enter a jax.profiler.TraceAnnotation when available (it is on
-    every jax we target, but keep the host path profiler-optional)."""
+    every jax we target, but keep the host path profiler-optional). The
+    profiler splits an arg's value at commas: join lists otherwise."""
     try:
         import jax.profiler as _prof
-        ann = _prof.TraceAnnotation(name)
+        ann = _prof.TraceAnnotation(name, **args)
         ann.__enter__()
         return ann
     except Exception:

@@ -5,6 +5,8 @@ contiguous cache bit-identity, p=0 ContinuousEngine == legacy ServeEngine
 greedy decode, preemption-recompute determinism, the TP decode exchange
 against the W-matrix oracle, and the serving telemetry schema.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +20,9 @@ from repro.netsim import NetConfig, request_trace
 from repro.serve import (BlockAllocator, ContinuousEngine, PagedCache,
                          Request, Scheduler, ServeEngine, TPDecodeConfig,
                          n_pages)
-from repro.serve.kvcache import NULL_BLOCK
+from repro.serve.engine import (PREFILL_PROGRAM, PREFILL_PROGRAMS,
+                                ROUND_PROGRAM, round_counts)
+from repro.serve.kvcache import NULL_BLOCK, WRITE_PROGRAM
 from repro.serve.scheduler import FINISHED, RUNNING, WAITING
 from repro.serve.tp import TPContext
 from repro.telemetry import Telemetry
@@ -324,26 +328,192 @@ def test_decode_plan_shape():
 # Telemetry + load generator
 # ---------------------------------------------------------------------------
 
+class _RoundLog:
+    """Wraps an engine's decode round to log each call's host copies of
+    the lanes' positions and tokens left."""
+
+    def __init__(self, fn):
+        self.fn, self.calls = fn, []
+
+    def __call__(self, params, pool, bt, tok, pos, n_left, key, ch):
+        self.calls.append((np.asarray(pos).copy(), np.asarray(n_left).copy()))
+        return self.fn(params, pool, bt, tok, pos, n_left, key, ch)
+
+
+def _round_sums(pos, n_left, chunk):
+    """The loop form of ``round_counts``: per token step, the live lanes
+    and the K/V positions each reads."""
+    steps = tokens = kv_reads = 0
+    for s in range(chunk):
+        live = [p + s for p, k in zip(pos.tolist(), n_left.tolist())
+                if k > s]
+        if live:
+            steps += 1
+            tokens += len(live)
+            kv_reads += sum(p + 1 for p in live)
+    return {"steps": steps, "tokens": tokens, "kv_reads": kv_reads}
+
+
+def _events(tel, name):
+    return [e for e in tel.trace.events if e["name"] == name]
+
+
 def test_serving_trace_schema(served, tmp_path):
     cfg, model, params = served
     rng = np.random.default_rng(4)
     tel = Telemetry()
     eng = ContinuousEngine(model, params, page=4, n_blocks=17, max_batch=2,
                            chunk=4, max_len=32, telemetry=tel)
-    reqs = _requests(cfg, 2, rng)
+    log = eng._round = _RoundLog(eng._round)
+    reqs = _requests(cfg, 3, rng)
     eng.run(reqs, drain=True)
     obj = tel.trace.to_chrome()
     assert validate_chrome_trace(obj) == []
     names = {e["name"] for e in obj["traceEvents"]}
-    assert {"serve.request", "serve.prefill", "serve.queue"} <= names
+    assert {"serve.request", "serve.prefill", "serve.queue", "serve.step",
+            "serve.admit", "serve.batch", "serve.round",
+            "serve.advance"} <= names
     spans = [e for e in obj["traceEvents"] if e["name"] == "serve.request"]
     assert {s["args"]["rid"] for s in spans} == {r.rid for r in reqs}
     q = [e for e in obj["traceEvents"] if e["name"] == "serve.queue"]
     assert {"waiting", "running", "kv_blocks_used", "kv_blocks_free"} \
         <= set(q[0]["args"])
+
+    # the span tree: every phase lies inside a serve.step, and the
+    # serve.queue counter outside every one
+    steps = [(e["ts"], e["ts"] + e["dur"]) for e in _events(tel, "serve.step")]
+    for name in ("serve.admit", "serve.prefill", "serve.batch",
+                 "serve.round", "serve.advance"):
+        for e in _events(tel, name):
+            assert any(s0 <= e["ts"] and e["ts"] + e["dur"] <= s1
+                       for s0, s1 in steps), (name, e)
+    for e in q:
+        assert not any(s0 < e["ts"] < s1 for s0, s1 in steps)
+    assert sum(e["args"]["admitted"] for e in _events(tel, "serve.admit")) \
+        == len(_events(tel, "serve.prefill"))
+    assert sum(e["args"]["finished"]
+               for e in _events(tel, "serve.advance")) <= len(reqs)
+    for e in _events(tel, "serve.prefill"):
+        assert e["args"]["programs"] == PREFILL_PROGRAMS
+
+    # serve.round's counters are the sums over the lanes the round ran
+    rounds = _events(tel, "serve.round")
+    assert len(rounds) == len(log.calls) > 0
+    for e, (pos, n_left) in zip(rounds, log.calls):
+        assert e["args"]["program"] == ROUND_PROGRAM
+        assert {k: e["args"][k] for k in ("steps", "tokens", "kv_reads")} \
+            == _round_sums(pos, n_left, eng.chunk)
+    assert [e["args"]["lanes"] for e in _events(tel, "serve.batch")] \
+        == [int((n > 0).sum()) for _, n in log.calls]
     path = tmp_path / "trace.json"
     tel.trace.write(str(path))
     assert path.exists()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_round_counts_match_loop(seed):
+    rng = np.random.default_rng(seed)
+    pos = rng.integers(0, 300, 6).astype(np.int32)
+    n_left = rng.integers(0, 12, 6).astype(np.int32)
+    n_left[rng.integers(0, 6)] = 0                      # an idle lane
+    assert round_counts(pos, n_left, 8) == _round_sums(pos, n_left, 8)
+
+
+def test_serving_prefill_spans_first_and_wait(served):
+    """``first`` is 1 on a request's first prefill and 0 on each
+    re-prefill after preemption; ``wait_ms`` is the prefill's start less
+    the request's due time, on the span's own clock."""
+    cfg, model, params = served
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, size=10),
+                    max_new=9, arrival_ms=5.0 * i) for i in range(3)]
+    tel = Telemetry()
+    eng = ContinuousEngine(model, params, page=4, n_blocks=9, max_batch=3,
+                           chunk=4, max_len=32, telemetry=tel)
+    rep = eng.run(reqs)
+    assert sum(r.n_preempt for r in rep.requests) > 0   # OOM actually hit
+    pre = _events(tel, "serve.prefill")
+    by_rid = {r.rid: [e for e in pre if e["args"]["rid"] == r.rid]
+              for r in rep.requests}
+    for r in rep.requests:
+        evs = by_rid[r.rid]
+        assert [e["args"]["first"] for e in evs] \
+            == [1] + [0] * r.n_preempt
+        first = evs[0]["args"]["wait_ms"]
+        assert 0.0 <= first <= r.admitted_ms - r.arrival_ms
+        assert all(e["args"]["wait_ms"] >= r.first_token_ms - r.arrival_ms
+                   for e in evs[1:])
+    # wait_ms + arrival_ms is the prefill's start on the run's clock: it
+    # differs from the span's wall-clock start by one offset for all
+    # (to within half the 5 ms between arrivals)
+    offsets = [e["ts"] / 1e3 - e["args"]["wait_ms"]
+               - reqs[e["args"]["rid"]].arrival_ms for e in pre]
+    assert max(offsets) - min(offsets) < 2.5
+
+
+class _StubTrace:
+    """Only what a profiler-backed stand-in offers the engine: ``span``
+    (a TraceAnnotation), ``complete``, ``now_us`` and ``counter``."""
+
+    def __init__(self):
+        self.counters = 0
+
+    @property
+    def trace(self):
+        return self
+
+    def span(self, name, **args):
+        return jax.profiler.TraceAnnotation(name, **args)
+
+    def complete(self, name, ts_us, dur_us, **args):
+        pass
+
+    def now_us(self):
+        return 0.0
+
+    def counter(self, name, values):
+        self.counters += 1
+
+
+@pytest.mark.parametrize("kind", ["buffer", "stub"])
+def test_serving_telemetry_bit_identical(served, kind):
+    """Served tokens are the same with telemetry off, with a
+    ``Telemetry``, and with a stand-in that has only the four calls the
+    engine may make."""
+    cfg, model, params = served
+    outs = []
+    for tel in (None, Telemetry() if kind == "buffer" else _StubTrace()):
+        rng = np.random.default_rng(5)
+        eng = ContinuousEngine(model, params, page=4, n_blocks=17,
+                               max_batch=2, chunk=4, max_len=32,
+                               telemetry=tel)
+        outs.append(eng.run(_requests(cfg, 3, rng), drain=True).outputs())
+    assert outs[0] == outs[1]
+    if kind == "stub":
+        assert tel.counters > 0
+
+
+def _module(lowered):
+    return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+
+def test_program_names_match_lowerings(served):
+    cfg, model, params = served
+    eng = ContinuousEngine(model, params, page=4, n_blocks=17, max_batch=2,
+                           chunk=4, max_len=32)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    assert _module(eng._prefill.lower(params, toks)) == PREFILL_PROGRAM
+    _, pcache = jax.eval_shape(eng._prefill, params, toks)
+    cache = PagedCache(model, page=4, n_blocks=17)
+    assert _module(cache._writer(8).lower(
+        cache.pool, pcache, jnp.zeros(8, jnp.int32))) == WRITE_PROGRAM
+    B = eng.max_batch
+    lanes = jnp.zeros(B, jnp.int32)
+    assert _module(eng._round.lower(
+        params, cache.pool, jnp.zeros((B, eng.max_pages), jnp.int32),
+        jnp.zeros((B, 1), jnp.int32), lanes, lanes,
+        jax.random.PRNGKey(0), None)) == ROUND_PROGRAM
+    assert PREFILL_PROGRAMS.split(";") == [PREFILL_PROGRAM, WRITE_PROGRAM]
 
 
 def test_request_trace_deterministic_and_in_range():

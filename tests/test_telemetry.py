@@ -1,11 +1,13 @@
 """Exchange telemetry (DESIGN.md §14): bit-identity of instrumented runs,
 per-link estimator convergence against every channel family, the drift
 monitor, Chrome-trace schema validity, and the tap/timer utilities."""
+import glob
 import json
 import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -217,6 +219,35 @@ def test_trace_buffer_emits_valid_chrome_trace(tmp_path):
         assert validate_chrome_trace(json.load(f)) == []
     names = [e["name"] for e in obj["traceEvents"]]
     assert {"phase.outer", "phase.inner", "marker"} <= set(names)
+
+
+def test_trace_buffer_span_reaches_profiler_on_its_clock(tmp_path):
+    """A span's args (those given at entry and those set at its end) reach
+    the profiler's annotation as numbers, and its ``ts`` is wall-clock µs
+    since the epoch, the clock of the profiler's host events."""
+    from jax.profiler import ProfileData
+    tb = TraceBuffer()
+    t_wall = time.time_ns() / 1e3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tb.span("phase.args", rid=7, wait_ms=1.5, program="jit_f") as sp:
+            sp.set_metadata(finished=2)
+    finally:
+        jax.profiler.stop_trace()
+    ev = tb.events[-1]
+    assert ev["args"] == {"rid": 7, "wait_ms": 1.5, "program": "jit_f",
+                          "finished": 2}
+    assert abs(ev["ts"] - t_wall) < 60e6           # epoch µs, not run µs
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    start, = [dict(pl.stats)["profile_start_time"] for pl in pd.planes
+              if pl.name == "Task Environment"]
+    hits = [e for pl in pd.planes if pl.name == "/host:CPU"
+            for ln in pl.lines for e in ln.events if e.name == "phase.args"]
+    assert len(hits) == 1
+    assert dict(hits[0].stats) == ev["args"]
+    assert abs((start + hits[0].start_ns) / 1e3 - ev["ts"]) < 1e3   # 1 ms
 
 
 def test_trace_validator_rejects_malformed():
