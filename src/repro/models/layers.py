@@ -308,52 +308,66 @@ def attention_decode(p, x, k_cache, v_cache, pos, *, cfg: ArchConfig,
 # Paged KV attention (DESIGN.md §18)
 # ---------------------------------------------------------------------------
 
-def paged_gather(pool, bt, page: int):
-    """Reconstruct the per-request contiguous cache view from the slot pool.
+def paged_gather(pool, layer, bt, page: int):
+    """Reconstruct one layer's per-request contiguous cache view from the
+    stacked slot pool.
 
-    pool: (n_slots, kvh, hd) flat token slots; bt: (B, P) int32 block table.
-    Returns (B, P·page, kvh, hd) where row i is the slot holding absolute
-    position i of that request — bit-identical to the contiguous cache when
-    the request's blocks were allocated in order (pinned by test). Unwritten
-    positions read whatever the pointed-to slot holds (block 0 = the null
-    block for unallocated pages); the decode mask hides them.
+    pool: (n_layers, n_slots, kvh, hd) flat token slots of every layer of
+    a kind; layer: the member index into its leading axis (a Python int or
+    a traced scalar); bt: (B, P) int32 block table. Returns
+    (B, P·page, kvh, hd) where row i is the slot holding absolute position
+    i of that request — bit-identical to the contiguous cache when the
+    request's blocks were allocated in order (pinned by test). One gather
+    of rows from the stacked buffer seen as (n_layers·n_slots, kvh, hd): no
+    layer-sized slice is made first, and the row index is 1-D, which a v5e
+    gathers faster than a (layer, slot) index pair (a decode step at
+    Mistral-7B widths, 24 lanes x 2560 positions: 39.2 against 41.5 ms).
+    Unwritten positions read whatever the pointed-to slot holds (block 0 =
+    the null block for unallocated pages); the decode mask hides them.
     """
     B, P = bt.shape
     slots = bt[:, :, None] * page + jnp.arange(page)[None, None, :]
-    return pool[slots.reshape(B, P * page)]
+    n_layers, n_slots = pool.shape[:2]
+    rows = pool.reshape((n_layers * n_slots,) + pool.shape[2:])
+    return rows[layer * n_slots + slots.reshape(B, P * page)]
 
 
-def paged_write(pool, new, bt, pos, page: int):
-    """Scatter one token's K or V into each request's slot at `pos`.
+def paged_write(pool, layer, new, bt, pos, page: int):
+    """Scatter one token's K or V into each request's slot at `pos`, in
+    layer `layer` of the stacked pool (n_layers, n_slots, kvh, hd).
 
     new: (B, 1, kvh, hd); pos: (B,) absolute positions. Inactive lanes point
     at the null block (id 0) and harmlessly overwrite its slots; active
     lanes own their blocks exclusively, so the scatter indices never collide
-    across live requests.
+    across live requests. The pool is the decode loop's state, so XLA
+    updates it in place. The index stays a (layer, slot) pair: a scatter
+    through the flat row view of ``paged_gather`` made XLA copy a pool
+    with a unit head axis.
     """
     B = bt.shape[0]
     flat = bt[jnp.arange(B), pos // page] * page + pos % page
-    return pool.at[flat].set(new[:, 0].astype(pool.dtype))
+    return pool.at[layer, flat].set(new[:, 0].astype(pool.dtype))
 
 
-def attention_decode_paged(p, x, pool_k, pool_v, pos, *, bt, page: int,
-                           cfg: ArchConfig, window=None, tp=None,
+def attention_decode_paged(p, x, pool_k, pool_v, layer, pos, *, bt,
+                           page: int, cfg: ArchConfig, window=None, tp=None,
                            tp_masks=None, site=None, key=None):
     """One-step decode against the paged pool: write the new token's K/V
-    through the block table, gather the contiguous view, attend with
-    per-request positions. `tp` (a serve.tp.TPContext) reroutes the output
-    projection through the drop-masked exchange — `site` indexes this
-    layer's collective's packet masks in `tp_masks`. Returns
-    (out, new_pool_k, new_pool_v)."""
+    into layer `layer` of the stacked pools through the block table, gather
+    that layer's contiguous view, attend with per-request positions. `tp`
+    (a serve.tp.TPContext) reroutes the output projection through the
+    drop-masked exchange — `site` indexes this layer's collective's packet
+    masks in `tp_masks`. Returns (out, new_pool_k, new_pool_v), the whole
+    stacked pools."""
     q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
     k = jnp.einsum("bsd,dhe->bshe", x, p["wk"])
     v = jnp.einsum("bsd,dhe->bshe", x, p["wv"])
     q = rope(q, pos[:, None], cfg.rope_theta)
     k = rope(k, pos[:, None], cfg.rope_theta)
-    pool_k = paged_write(pool_k, k, bt, pos, page)
-    pool_v = paged_write(pool_v, v, bt, pos, page)
-    kc = paged_gather(pool_k, bt, page)
-    vc = paged_gather(pool_v, bt, page)
+    pool_k = paged_write(pool_k, layer, k, bt, pos, page)
+    pool_v = paged_write(pool_v, layer, v, bt, pos, page)
+    kc = paged_gather(pool_k, layer, bt, page)
+    vc = paged_gather(pool_v, layer, bt, page)
     out = decode_attention(q, kc, vc, pos, window=window, ring=False)
     if tp is None:
         out = jnp.einsum("bshe,hed->bsd", out, p["wo"])

@@ -44,10 +44,13 @@ class KindSpec:
     # paged serving path (DESIGN.md §18) — optional; kinds without it
     # cannot serve through the continuous-batching engine
     decode_paged: Optional[Callable[..., Any]] = None
-    # (p, x, cache_l, pos, aux, cfg) -> (x, new_cache_l); cache_l is this
-    # layer's slice of the slot pool: {"k"/"v": (n_slots, kvh, hd),
-    # "layer_id": i32 scalar}; pos is (B,) per-request positions and
-    # aux["paged"] carries the block table / page size / exchange hooks
+    # (p, x, pool, m, pos, aux, cfg) -> (x, new_pool); pool is the kind's
+    # whole stacked slot pool: {"k"/"v": (n_members, n_slots, kvh, hd),
+    # "layer_id": (n_members,) i32}, m the member index of this layer (a
+    # traced scalar in a scan, a Python int unrolled); the step updates
+    # member m in place and returns the whole pool. pos is (B,) per-request
+    # positions and aux["paged"] carries the block table / page size /
+    # exchange hooks
     paged_spec: Optional[Callable[..., Any]] = None
     # (cfg, n_slots) -> per-layer pool pytree
 
@@ -88,7 +91,7 @@ def _remat_group_size(n_layers: int) -> int:
 
 def _scan_group(spec: KindSpec, stacked, x, aux, cfg, mode: str,
                 cache=None, pos=None, remat: bool = True):
-    """Run one kind group. mode in {train, prefill, decode}."""
+    """Run one kind group. mode in {train, prefill, decode, decode_paged}."""
     if mode == "train":
         def body(carry, p):
             h, aloss = carry
@@ -127,17 +130,31 @@ def _scan_group(spec: KindSpec, stacked, x, aux, cfg, mode: str,
             return h, cache_l
         x, cache_stack = jax.lax.scan(body, x, stacked)
         return x, cache_stack
-    # decode / decode_paged
-    step = spec.decode if mode == "decode" else spec.decode_paged
-    if step is None:
-        raise ValueError(f"kind {spec.name!r} has no paged decode path")
+    if mode == "decode":
+        def body(h, pc):
+            p, cache_l = pc
+            h, new_cache = spec.decode(p, h, cache_l, pos, aux, cfg)
+            return h, new_cache
+        x, new_cache = jax.lax.scan(body, x, (stacked, cache))
+        return x, new_cache
+    # decode_paged: the stacked pool is loop state, and each layer updates
+    # its member in place; as scan xs/ys it would be copied every layer
+    step = _paged_step(spec)
 
-    def body(h, pc):
-        p, cache_l = pc
-        h, new_cache = step(p, h, cache_l, pos, aux, cfg)
-        return h, new_cache
-    x, new_cache = jax.lax.scan(body, x, (stacked, cache))
-    return x, new_cache
+    def body(carry, pm):
+        h, pool = carry
+        p, m = pm
+        return step(p, h, pool, m, pos, aux, cfg), None
+    n = cache["layer_id"].shape[0]
+    (x, pool), _ = jax.lax.scan(body, (x, cache),
+                                (stacked, jnp.arange(n, dtype=jnp.int32)))
+    return x, pool
+
+
+def _paged_step(spec: KindSpec):
+    if spec.decode_paged is None:
+        raise ValueError(f"kind {spec.name!r} has no paged decode path")
+    return spec.decode_paged
 
 
 def apply_stack(params, x, aux, cfg: ArchConfig, kinds: Sequence[str],
@@ -174,6 +191,7 @@ def apply_stack(params, x, aux, cfg: ArchConfig, kinds: Sequence[str],
     group_pos = {k: 0 for k in layout}
     aux_acc = jnp.float32(0.0)
     caches: Dict[str, list] = {k: [] for k in layout}
+    pools = dict(cache) if mode == "decode_paged" else None
     for kname in kinds:
         i = group_pos[kname]
         group_pos[kname] += 1
@@ -185,15 +203,17 @@ def apply_stack(params, x, aux, cfg: ArchConfig, kinds: Sequence[str],
         elif mode == "prefill":
             x, c = spec.prefill(p, x, aux, cfg)
             caches[kname].append(c)
+        elif mode == "decode_paged":
+            x, pools[kname] = _paged_step(spec)(p, x, pools[kname], i, pos,
+                                                aux, cfg)
         else:
-            step = spec.decode if mode == "decode" else spec.decode_paged
-            if step is None:
-                raise ValueError(f"kind {kname!r} has no paged decode path")
             cache_l = jax.tree.map(lambda a, i=i: a[i], cache[kname])
-            x, c = step(p, x, cache_l, pos, aux, cfg)
+            x, c = spec.decode(p, x, cache_l, pos, aux, cfg)
             caches[kname].append(c)
     if mode == "train":
         return x, aux_acc
+    if mode == "decode_paged":
+        return x, pools
     out_cache = {k: jax.tree.map(lambda *xs: jnp.stack(xs), *v)
                  for k, v in caches.items() if v}
     return x, out_cache
@@ -216,10 +236,10 @@ def init_paged(cfg: ArchConfig, kinds: Sequence[str],
     """{kind: stacked slot pool} for the paged serving path (DESIGN.md §18).
 
     Each kind's pool carries a ``"layer_id"`` leaf — the faithful layer
-    index of every group member. The grouped decode scans over the cache,
-    so per-layer data (which collective site's drop masks apply) must ride
-    inside it: ``aux`` is closed over by the scan body and cannot vary per
-    layer.
+    index of every group member. The grouped decode carries the pool
+    through its scan and reads ``layer_id`` at the member index, so
+    per-layer data (which collective site's drop masks apply) rides inside
+    it: ``aux`` is closed over by the scan body and cannot vary per layer.
     """
     layout = group_layout(kinds)
     out = {}

@@ -82,12 +82,12 @@ def make_dense_kind(kind_name: str) -> KindSpec:
         x, _ = _ffn(p, x, cfg)
         return x, {"k": kc, "v": vc}
 
-    def decode_paged(p, x, cache_l, pos, aux, cfg: ArchConfig):
+    def decode_paged(p, x, pool, m, pos, aux, cfg: ArchConfig):
         pg = aux["paged"]
         tp = pg.get("tp")
-        li = cache_l["layer_id"]
+        li = pool["layer_id"][m]
         h, kc, vc = L.attention_decode_paged(
-            p["attn"], L.rms_norm(x, p["ln1"]), cache_l["k"], cache_l["v"],
+            p["attn"], L.rms_norm(x, p["ln1"]), pool["k"], pool["v"], m,
             pos, bt=pg["bt"], page=pg["page"], cfg=cfg, window=window,
             tp=tp, tp_masks=pg.get("masks"), site=2 * li, key=pg.get("key"))
         x = x + h
@@ -100,7 +100,7 @@ def make_dense_kind(kind_name: str) -> KindSpec:
             out = tp.combine_mlp(p["mlp"], L.rms_norm(x, p["ln2"]),
                                  pg.get("masks"), 2 * li + 1, pg.get("key"))
             x = x + out
-        return x, {"k": kc, "v": vc, "layer_id": li}
+        return x, {**pool, "k": kc, "v": vc}
 
     def cache_spec(cfg: ArchConfig, batch: int, max_len: int):
         C = min(window, max_len) if window is not None else max_len
