@@ -25,6 +25,8 @@ import os
 import sys
 import time
 
+import numpy as np
+
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import harness  # noqa: E402
@@ -129,6 +131,91 @@ def serve_open_loop(cell, seeds, n_control, n_faults, lowp, seconds,
     return rows, ("logit_gap",)
 
 
+def other_masks(sim, seed31, masks):
+    """The masks the step would have drawn under another key (each step's
+    key folded with a tag), the first tag whose masks differ from
+    ``masks`` in some step: masks equal to the step's are no fault."""
+    import jax
+    _, key = jax.random.split(jax.random.PRNGKey(seed31))
+    for tag in range(1, 64):
+        out = []
+        for t in range(len(masks)):
+            k = jax.random.fold_in(jax.random.fold_in(key, t), tag)
+            out.append(tuple(np.asarray(m) for m in
+                             sim._masks(k, sim.ch_state)))
+        if any((a != b).any() for pair, mine in zip(masks, out)
+               for a, b in zip(pair, mine)):
+            return out, tag
+    raise RuntimeError("no other key changes the masks")
+
+
+def train_sim(cell, seeds, n_control, n_faults, lowp):
+    """Program readings (each seed the cell's checked steps through its
+    own step object, against the reference), the control's (the
+    reference in ``lowp`` put in the program's place) and, on the first
+    ``n_faults`` seeds, each fault of a training step planted in the
+    reference put in the program's place: half the batch left out, the
+    exchange left out, the masks of another key; a state left unchanged
+    reads 1 by the measure's definition and is computed, not run. On the
+    control's seeds also ``backward_lowp``: the reference with only its
+    backward pass in ``lowp``, which the limits have to fail as well."""
+    import jax.numpy as jnp
+    import markov
+    from drivers import train_sim as drv
+    from refs import train as ref_train
+
+    hf, tr = cell.config, cell.traffic
+    n, B, S = int(tr["workers"]), int(tr["batch_per_worker"]), \
+        int(tr["seq_len"])
+    checked, lr = int(tr["check_steps"]), tr["simulator"]["lr"]
+    limits = tr["limits"]
+    numbers = ("loss_gap", "change1_gap", "change_gap")
+    lim = {k: limits[k] for k in numbers}
+    sim = drv.Sim(hf, tr)
+    ref = ref_train.Reference(hf)
+    variants = {"control": ref_train.Reference(hf, lowp=jnp.dtype(lowp)),
+                "backward_lowp": ref_train.Reference(
+                    hf, lowp=jnp.dtype(lowp), backward_only=True),
+                "half_batch": ref_train.Reference(hf, half_batch=True),
+                "no_exchange": ref_train.Reference(hf, exchange=False)}
+    rows = []
+
+    def row(kind, seed, g, **kw):
+        vals = {k: g[k] for k in numbers}
+        rows.append(emit(kind=kind, seed=seed, **vals,
+                         correct=verdict(vals, lim), worst=g.get("worst"),
+                         **kw))
+
+    for i, seed in enumerate(seeds):
+        s31 = harness.seed32(seed)
+        tokens, labels = markov.batches(s31, tr["task"], n, B, S)
+        masks, losses, prog = drv.checked_steps(sim, s31, tokens, labels,
+                                                checked)
+        finite = bool(np.all(np.isfinite(prog["loss"])))
+        sim.params = sim.opt_state = None
+        del losses
+        gc.collect()
+        args = (s31, tokens[:checked], labels[:checked])
+        truth = ref.run(*args, masks, lr, checked)
+        row("program", seed, ref_train.gaps(prog, truth), finite=finite)
+        if i < n_control:
+            for name in ("control", "backward_lowp"):
+                row(name, seed, ref_train.gaps(
+                    variants[name].run(*args, masks, lr, checked), truth))
+        if i < n_faults:
+            for name in ("half_batch", "no_exchange"):
+                row(name, seed, ref_train.gaps(
+                    variants[name].run(*args, masks, lr, checked), truth))
+            wrong, tag = other_masks(sim, s31, masks)
+            row("other_masks", seed, ref_train.gaps(
+                ref.run(*args, wrong, lr, checked), truth), tag=tag)
+            still = {"loss": prog["loss"], "change": {
+                t: {k: 0.0 * v for k, v in c.items()}
+                for t, c in truth["change"].items()}}
+            row("unchanged", seed, ref_train.gaps(still, truth))
+    return rows, numbers
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -160,6 +247,9 @@ def main(argv=None) -> int:
         rows, numbers = serve_open_loop(cell, seeds, args.control,
                                         args.faults, args.lowp, seconds,
                                         rates)
+    elif kind == "train_sim":
+        rows, numbers = train_sim(cell, seeds, args.control, args.faults,
+                                  args.lowp)
     else:
         print(f"calibrate: no readings for driver {kind!r}", file=sys.stderr)
         return 2
